@@ -50,14 +50,15 @@ least the recount fraction depth 1 hides (full-size sweeps on
 
 **Devices sweep** — the same fixed-size scenario (``FLEET_BENCH_SHARD_SATS``,
 default 8 satellites) executed by the sharded fleet runtime at 1/2/4
-devices (``FLEET_BENCH_DEVICES``). Each device count runs in a fresh
-subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-(the flag must precede jax init), placing the fleet's stacked arrays
-along the ``sats`` mesh axis. Ingest runs the vmapped dedup core — no
+devices (``FLEET_BENCH_DEVICES``), every arm in this one process on a
+``sats`` mesh over the first N visible devices; arms asking for more
+devices than are visible are recorded as skipped. On the CPU, several
+devices come from ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+set before the run starts. Ingest runs the vmapped dedup core — no
 per-satellite Python loop — at every device count. The parity gate:
 per-tile predictions and per-sat summaries across ALL device counts
 must match the single-device arm within ``SHARD_PARITY_TOL`` (0.0 — the
-documented bit-equal-on-CPU dedup tolerance; ``run.py fleet --strict``
+documented bit-equal-on-CPU dedup tolerance; ``run.py fleet``
 turns a violation into a nonzero exit). On forced host devices the
 "devices" share one CPU's cores, so sharded wall-clock mostly
 demonstrates structure (real gains need real accelerators); the
@@ -113,7 +114,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -548,8 +548,7 @@ def _jitguard_sweep(rows, report):
     machine-independent (like the transfer-cache churn gate), so the
     gate is enforced everywhere — a single recompile in steady state is
     the shape-churn class PR 9 eliminated. ``FLEET_BENCH_JITGUARD_SATS=0``
-    disables; on jax builds with no compilation-count source the gate
-    reports null."""
+    disables."""
     from benchmarks.common import counters
     from repro.analysis.jitguard import JitGuard
     from repro.core.fleet import Fleet
@@ -568,25 +567,23 @@ def _jitguard_sweep(rows, report):
     harvest = rnd.harvest_per_sat(n_sats)
     fl = Fleet(space, ground, pcfg, n_sats=n_sats)
 
-    per_round, mode = [], "unsupported"
+    per_round = []
     for k in range(n_rounds):
         with JitGuard(f"fleet round {k + 1}") as g:
             fl.ingest(frames, harvest)
-        mode = g.mode
-        per_round.append(g.compilations if g.supported else None)
+        per_round.append(g.compilations)
     fl.finalize()
 
-    supported = mode != "unsupported"
-    steady = sum(per_round[1:]) if supported else None
+    steady = sum(per_round[1:])
     row = {
-        "n_sats": n_sats, "rounds": n_rounds, "counter_mode": mode,
+        "n_sats": n_sats, "rounds": n_rounds,
         "recompiles_per_round": per_round,
         "warmup_round_compiles": per_round[0],
         "steady_rounds_compiles": steady,
     }
     report["jitguard"] = row
     rows.append(("fleet_jitguard", 0.0,
-                 f"mode={mode} warmup={per_round[0]} steady={steady}"))
+                 f"warmup={per_round[0]} steady={steady}"))
     return row
 
 
@@ -761,20 +758,15 @@ def _best_pair(fn_a, fn_b, iters):
     return min(ts_a), out_a, min(ts_b), out_b
 
 
-def _child_devices(n_devices: int) -> None:
-    """Run the sharded arm at ``n_devices`` and dump timings +
-    per-tile predictions JSON (spawned with the forced-host-device
-    XLA flag already in the environment)."""
-    import jax
+def _devices_arm(n_devices: int) -> dict:
+    """Run the sharded arm on the first ``n_devices`` devices; timings
+    + per-tile predictions."""
     import numpy as np
 
     from benchmarks.common import counters
     from repro.core.fleet import run_scenario
     from repro.core.fleet_sharding import sats_mesh
     from repro.core.pipeline import PipelineConfig
-
-    assert len(jax.devices()) >= n_devices, (
-        f"{len(jax.devices())} devices visible, {n_devices} requested")
     from repro.data.scenarios import generate_scenario
 
     n_sats = int(os.environ.get("FLEET_BENCH_SHARD_SATS", "8"))
@@ -791,7 +783,7 @@ def _child_devices(n_devices: int) -> None:
         lambda: run_scenario(space, ground, pcfg, sc, fleet=True, mesh=mesh),
         iters)
     summary = fleet.summary()
-    json.dump({
+    return {
         "n_devices": n_devices,
         "fleet_s": t,
         "tiles": int(sum(r.tiles_total for r in res)),
@@ -799,25 +791,7 @@ def _child_devices(n_devices: int) -> None:
         "tiles_per_s": summary["tiles_per_s"],
         "preds": [np.asarray(r.per_tile_pred).tolist() for r in res],
         "summaries": [r.summary() for r in res],
-    }, sys.stdout)
-
-
-def _spawn_devices(n_devices: int) -> dict:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    flag = f"--xla_force_host_platform_device_count={n_devices}"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-    p = subprocess.run(
-        [sys.executable, "-m", "benchmarks.fleet_bench",
-         "--child-devices", str(n_devices)],
-        cwd=root, env=env, capture_output=True, text=True)
-    if p.returncode != 0:
-        raise RuntimeError(f"fleet_bench child devices={n_devices} "
-                           f"failed:\n{p.stderr[-4000:]}")
-    return json.loads(p.stdout)
+    }
 
 
 def _size_sweep(rows, report):
@@ -873,7 +847,13 @@ def _devices_sweep(rows, report):
         # the parity gate and speedup_vs_1dev are defined against the
         # single-device arm — always run it, whatever the env asked for
         devices = (1, *devices)
-    arms = [_spawn_devices(d) for d in sorted(set(devices))]
+    import jax
+    visible = len(jax.devices())
+    skipped = sorted(d for d in set(devices) if d > visible)
+    if skipped:
+        report["devices_skipped"] = {"requested": skipped,
+                                     "visible": visible}
+    arms = [_devices_arm(d) for d in sorted(set(devices)) if d <= visible]
     base = arms[0]
     max_dev = 0.0
     for arm in arms:
@@ -997,13 +977,10 @@ def run(json_path: str = None):
                                      if jitg else None),
         "jit_steady_rounds_compiles": (jitg["steady_rounds_compiles"]
                                        if jitg else None),
-        "jit_counter_mode": jitg["counter_mode"] if jitg else None,
         # count-based, so machine-independent: enforced EVERYWHERE
-        # (null only when disabled or the jax build exposes no counter)
+        # (null only when disabled)
         "gate_jit_steady_state": (
-            jitg["steady_rounds_compiles"] == 0
-            if jitg and jitg["steady_rounds_compiles"] is not None
-            else None),
+            jitg["steady_rounds_compiles"] == 0 if jitg else None),
         "depth_pred_max_dev": depth["pred_max_dev"] if depth else None,
         "depth_hidden_fracs": (
             {d: v["hidden_frac"] for d, v in depth["per_depth"].items()}
@@ -1034,7 +1011,7 @@ def run(json_path: str = None):
                  f"shard_dev={shard_dev}"))
     with open(json_path, "w") as f:
         json.dump(report, f, indent=2)
-    # fail loudly AFTER the report lands on disk (run.py --strict turns
+    # fail loudly AFTER the report lands on disk (run.py turns
     # any gate into a nonzero exit); smoke configs without an 8-sat row
     # or a full-size contact sweep skip the perf gates by design, and so
     # do sub-``PERF_GATES_MIN_CORES`` boxes (gate value null, see the
@@ -1130,17 +1107,11 @@ def run(json_path: str = None):
 
 
 if __name__ == "__main__":
-    if "--child-devices" in sys.argv:
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "src"))
-        _child_devices(int(sys.argv[sys.argv.index("--child-devices") + 1]))
-    else:
-        if "--devices" in sys.argv:  # e.g. --devices 1,2,4
-            os.environ["FLEET_BENCH_DEVICES"] = \
-                sys.argv[sys.argv.index("--devices") + 1]
-        if "--stations" in sys.argv:  # e.g. --stations 8
-            os.environ["FLEET_BENCH_STATIONS"] = \
-                sys.argv[sys.argv.index("--stations") + 1]
-        for name, us, derived in run():
-            print(f"{name},{us:.1f},{derived}")
+    if "--devices" in sys.argv:  # e.g. --devices 1,2,4
+        os.environ["FLEET_BENCH_DEVICES"] = \
+            sys.argv[sys.argv.index("--devices") + 1]
+    if "--stations" in sys.argv:  # e.g. --stations 8
+        os.environ["FLEET_BENCH_STATIONS"] = \
+            sys.argv[sys.argv.index("--stations") + 1]
+    for name, us, derived in run():
+        print(f"{name},{us:.1f},{derived}")

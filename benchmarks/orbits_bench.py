@@ -23,7 +23,7 @@ scenario path feeds the contact tier.
 
 Writes ``BENCH_orbits.json`` (redirect with ``ORBITS_BENCH_JSON`` —
 smoke configs must not clobber the committed full-size report). Gate
-failures raise AFTER the report lands, so ``run.py orbits --strict``
+failures raise AFTER the report lands, so ``run.py orbits``
 exits nonzero while the JSON still records what happened.
 """
 from __future__ import annotations
@@ -181,7 +181,7 @@ def run(json_path: str = None):
                  f"skew={report['passes']['p90_over_p50']:.2f}x"))
     with open(json_path, "w") as f:
         json.dump(report, f, indent=2)
-    # gates raise AFTER the report lands (run.py --strict semantics)
+    # gates raise AFTER the report lands (run.py then exits nonzero)
     if report["_summary"]["gate_throughput"] is False:
         raise AssertionError(
             f"propagation throughput gate: "

@@ -8,14 +8,16 @@ Two fig11-style (dataset-analogue, unlimited-downlink) workloads:
   gate (per-tile predictions bit-identical-or-within-1e-5). Both arms
   run INTERLEAVED in ONE subprocess, each cell warmed once and then
   timed best-of-2 — steady-state throughput. (Cold-cache isolation is
-  pointless here, and sequential whole-arm subprocesses measured
-  minutes apart pick up >2x machine-speed drift on throttled CI boxes,
-  which used to swamp the per-cell signal.)
+  pointless here, and sequential whole arms measured minutes apart pick
+  up >2x machine-speed drift on throttled CI boxes, which used to swamp
+  the per-cell signal.)
 * **pass sequence** — successive targetfuse runs over frame sets of
   VARYING size per dataset, like successive orbital passes. This is the
   headline number and is deliberately timed cold, single-shot, each arm
-  in a fresh subprocess so neither inherits the other's XLA compile
-  cache: every pass presents new array shapes, so the seed path
+  starting from cleared in-memory compile caches with the persistent
+  compile cache off, so neither inherits the other's compiled programs
+  (all arms run in one process: one process per chip). Every pass
+  presents new array shapes, so the seed path
   recompiles its counting/ROI programs per pass while the engine's
   fixed-shape programs (frame buckets, size-tiered count batches) are
   compiled once, ever — the per-distinct-shape recompiles are exactly
@@ -27,8 +29,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 
 METHODS = ("space_only", "ground_only", "tiansuan", "kodan", "targetfuse")
 UNLIMITED = dict(bandwidth_mbps=100000.0, contact_s=3600.0)
@@ -44,9 +44,9 @@ PASSES = {
 JSON_PATH = "BENCH_pipeline.json"
 
 
-def _child(arm: str) -> None:
+def _arm(arm: str) -> dict:
     """``sweep``: both arms interleaved, steady-state. ``ref`` /
-    ``engine``: that arm's cold pass sequence. Dumps JSON to stdout."""
+    ``engine``: that arm's cold pass sequence."""
     import time
 
     import numpy as np
@@ -85,8 +85,7 @@ def _child(arm: str) -> None:
                         "cmae": r.cmae,
                         "pred": np.asarray(r.per_tile_pred).tolist(),
                     }
-        json.dump(out, sys.stdout)
-        return
+        return out
 
     use_engine = arm == "engine"
     out = {"passes": {}}
@@ -105,32 +104,25 @@ def _child(arm: str) -> None:
                 "tiles_per_s": r.tiles_total / dt,
                 "pred": np.asarray(r.per_tile_pred).tolist(),
             }
-    json.dump(out, sys.stdout)
+    return out
 
 
-def _spawn(arm: str) -> dict:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    p = subprocess.run(
-        [sys.executable, "-m", "benchmarks.pipeline_bench", "--child", arm],
-        cwd=root, env=env, capture_output=True, text=True)
-    if p.returncode != 0:
-        raise RuntimeError(f"pipeline_bench child '{arm}' failed:\n{p.stderr[-4000:]}")
-    return json.loads(p.stdout)
+def _cold(arm: str) -> dict:
+    """One cold pass-sequence arm: nothing compiled before it counts."""
+    import jax
+
+    from repro.launch import compile_cache
+    jax.clear_caches()
+    with compile_cache.disabled():
+        return _arm(arm)
 
 
 def run(json_path: str = JSON_PATH):
     import numpy as np
 
-    from benchmarks.common import counters
-    counters()  # train/cache once; the child processes just load
-
-    sweep = _spawn("sweep")
-    ref = _spawn("ref")
-    eng = _spawn("engine")
+    sweep = _arm("sweep")
+    ref = _cold("ref")
+    eng = _cold("engine")
 
     rows, report, max_dev = [], {"sweep": {}, "passes": {}}, 0.0
 
@@ -189,8 +181,5 @@ def run(json_path: str = JSON_PATH):
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        _child(sys.argv[sys.argv.index("--child") + 1])
-    else:
-        for name, us, derived in run():
-            print(f"{name},{us:.1f},{derived}")
+    for name, us, derived in run():
+        print(f"{name},{us:.1f},{derived}")

@@ -4,10 +4,11 @@ Prints ``name,us_per_call,derived`` CSV. Select figures with
 ``python -m benchmarks.run fig7 fig11`` (all by default). Pass
 ``--json PATH`` to also write the rows as a ``name ->
 {us_per_call, derived}`` dict (the ``BENCH_*.json`` trajectory files).
-By default a module that raises is reported as an ERROR row and the
-harness keeps going (exit 0); ``--strict`` makes any module failure
-exit nonzero — CI smoke runs use it so bench-embedded gates (e.g. the
-fleet/loop parity assert) actually fail the build.
+A module that raises is reported as an ERROR row and the harness keeps
+going through the later modules, then exits nonzero: a bench-embedded
+gate (e.g. the fleet/loop parity assert) always fails the run.
+Compiled programs persist in the compile cache
+(:mod:`repro.launch.compile_cache`).
 """
 from __future__ import annotations
 
@@ -20,10 +21,9 @@ FIGS = ("fig4", "fig6", "fig7", "fig8", "fig9", "fig10", "fig11", "fig12",
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     argv = sys.argv[1:]
-    strict = "--strict" in argv
-    if strict:
-        argv.remove("--strict")
     json_path = None
     if "--json" in argv:
         i = argv.index("--json")
@@ -91,8 +91,8 @@ def main() -> None:
         with open(json_path, "w") as f:
             json.dump(results, f, indent=2)
         print(f"# wrote {json_path}", file=sys.stderr)
-    if strict and failed:
-        sys.exit(f"--strict: benchmark module(s) failed: {', '.join(failed)}")
+    if failed:
+        sys.exit(f"benchmark module(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

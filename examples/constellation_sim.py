@@ -56,6 +56,7 @@ from repro.data.scenarios import (FleetScenarioSpec, GroundStation,
                                   generate_scenario)
 from repro.data.synthetic import SceneSpec
 from repro.launch.serve import get_counters
+from repro.launch import compile_cache
 
 
 def main():
@@ -251,4 +252,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

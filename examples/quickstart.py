@@ -21,6 +21,7 @@ from repro.core.pipeline import PipelineConfig
 from repro.core.policies import available_policies
 from repro.data.synthetic import SceneSpec, make_scene, revisit_frames
 from repro.launch.serve import get_counters
+from repro.launch import compile_cache
 
 
 def main():
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

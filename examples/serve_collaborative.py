@@ -27,6 +27,7 @@ from repro.core.fleet import Fleet
 from repro.core.pipeline import PipelineConfig
 from repro.data.synthetic import SceneSpec, make_scene, revisit_frames
 from repro.launch.serve import get_counters
+from repro.launch import compile_cache
 from repro.runtime.supervisor import DeadlineBatcher
 
 
@@ -96,4 +97,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

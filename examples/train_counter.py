@@ -19,6 +19,7 @@ from repro.core.metrics import cmae
 from repro.core.cascade import count_tiles_batched
 from repro.core import tiling
 from repro.data.synthetic import SceneSpec, make_scene, tile_counts
+from repro.launch import compile_cache
 import jax.numpy as jnp
 
 
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -12,25 +12,17 @@ benches and tests can assert the steady state compiles nothing:
         fleet.ingest(frames, harvest)          # round >= 2, fixed sizes
     g.assert_steady_state("fleet round 3")     # raises if g.compilations
 
-Primary signal: ``jax.monitoring`` duration events — jax emits
+The signal is ``jax.monitoring`` duration events — jax emits
 ``/jax/core/compile/backend_compile_duration`` once per backend
-compilation (verified: cache hits emit nothing).  Fallback when the
-monitoring listener API is unavailable: the miss counter of jax's
-parameter-inference lru cache (``_infer_params_cached``), which grows
-exactly when a jitted call sees a novel (function, shapes) key.  The
-fallback over-approximates compilations (tracing-cache misses), which is
-safe for a zero-gate; ``mode`` records which signal counted.
+compilation (verified: cache hits emit nothing).
 """
 from __future__ import annotations
 
 import threading
 
+import jax.monitoring as mon
+
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/backend_compile"
-
-
-def _lru_misses() -> int:
-    from jax._src import pjit as _pjit
-    return int(_pjit._infer_params_cached.cache_info().misses)
 
 
 class JitGuard:
@@ -44,56 +36,24 @@ class JitGuard:
     def __init__(self, label: str = ""):
         self.label = label
         self.compilations = 0
-        self.mode: str = "inactive"
         self._lock = threading.Lock()
-        self._active = False
-        self._cb = None
-        self._base = 0
+
+    def _on_duration(self, name: str, secs: float, **kw) -> None:
+        if name.startswith(_COMPILE_EVENT_PREFIX):
+            with self._lock:
+                self.compilations += 1
 
     def __enter__(self) -> "JitGuard":
         self.compilations = 0
-        try:
-            import jax.monitoring as mon
-
-            def _on_duration(name: str, secs: float, **kw) -> None:
-                if self._active and name.startswith(_COMPILE_EVENT_PREFIX):
-                    with self._lock:
-                        self.compilations += 1
-
-            mon.register_event_duration_secs_listener(_on_duration)
-            self._cb = _on_duration
-            self.mode = "monitoring"
-        except Exception:
-            try:
-                self._base = _lru_misses()
-                self.mode = "lru-fallback"
-            except Exception:
-                self.mode = "unsupported"
-        self._active = True
+        mon.register_event_duration_secs_listener(self._on_duration)
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._active = False
-        if self.mode == "monitoring":
-            try:
-                from jax._src import monitoring as _impl
-                _impl._unregister_event_duration_listener_by_callback(
-                    self._cb)
-            except Exception:
-                pass  # listener stays registered but inert (_active False)
-            self._cb = None
-        elif self.mode == "lru-fallback":
-            self.compilations = max(0, _lru_misses() - self._base)
+        mon.unregister_event_duration_listener(self._on_duration)
         return False
-
-    @property
-    def supported(self) -> bool:
-        return self.mode in ("monitoring", "lru-fallback")
 
     def assert_steady_state(self, what: str = "") -> None:
         """Raise if the guarded block compiled any new XLA program."""
-        if not self.supported:
-            return
         if self.compilations:
             label = what or self.label or "guarded block"
             raise AssertionError(

@@ -36,16 +36,20 @@ def count_tiles(params, cfg: DetectorConfig, tiles, score_thresh: float = 0.3,
     return _count_tiles_body(params, cfg, tiles, score_thresh, nms_iou)
 
 
-@partial(jax.jit, static_argnames=("cfg", "score_thresh", "nms_iou"))
+@partial(jax.jit, static_argnames=("cfg", "score_thresh", "nms_iou",
+                                   "mesh"))
 def _count_tiles_chunks(params, cfg: DetectorConfig, chunks,
-                        score_thresh: float, nms_iou: float):
+                        score_thresh: float, nms_iou: float, mesh=None):
     """:func:`count_tiles` vmapped over a stacked (n_chunks, batch, ...)
-    axis; with the chunk axis placed along a ``sats`` device mesh, each
-    device counts its share of the fleet's batches in parallel. The
-    detector is per-sample, so per-chunk outputs are bit-equal to
+    axis; with the chunk axis placed along a ``sats`` device ``mesh``,
+    each device counts its share of the fleet's batches in parallel.
+    The detector is per-sample, so per-chunk outputs are bit-equal to
     looping the single-chunk program."""
-    return jax.vmap(lambda t: _count_tiles_body(params, cfg, t,
-                                                score_thresh, nms_iou))(chunks)
+    from repro.core.fleet_sharding import map_lanes
+    count = jax.vmap(lambda p, t: _count_tiles_body(p, cfg, t, score_thresh,
+                                                    nms_iou),
+                     in_axes=(None, 0))
+    return map_lanes(count, mesh, replicated=1)(params, chunks)
 
 
 def _tier_batch(n: int, batch: int, floor: int = 8) -> int:
@@ -91,12 +95,14 @@ def _count_forward(params, cfg, t, batch: int, score_thresh, nms_iou,
             t = jnp.concatenate(
                 [t, jnp.zeros((n_stack - n_chunks, *t.shape[1:]), t.dtype)])
         c, f = _count_tiles_chunks(params, cfg, sh.device_put(t),
-                                   score_thresh, nms_iou)
+                                   score_thresh, nms_iou, mesh=sh.mesh)
         out = jnp.stack([c[:n_chunks].reshape(-1),
                          f[:n_chunks].reshape(-1)])
         # analysis: waive(host-sync): the designated single host copy of a
         # counting batch; callers passing defer=True skip even this one
         return out if defer else np.asarray(out)
+    from repro.core.fleet_sharding import on_one_device
+    t = on_one_device(t)
     outs_c, outs_f = [], []
     for i in range(n_chunks):
         c, f = count_tiles(params, cfg, t[i], score_thresh, nms_iou)

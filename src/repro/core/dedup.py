@@ -230,8 +230,9 @@ _dedup_finalize = jax.jit(_dedup_finalize_body)
 
 # --- vmapped multi-satellite core (one call per bucket, no per-sat loop) ---
 
-@partial(jax.jit, static_argnames=("k_pad", "iters"))
-def _dedup_multi_core(m_pad, n, k, key, *, k_pad: int, iters: int):
+@partial(jax.jit, static_argnames=("k_pad", "iters", "mesh"))
+def _dedup_multi_core(m_pad, n, k, key, *, k_pad: int, iters: int,
+                      mesh=None):
     """:func:`_dedup_core_body` batched over a leading sat axis.
 
     Inputs stack one satellite per leading row: ``m_pad`` (S, n_pad, D),
@@ -240,13 +241,18 @@ def _dedup_multi_core(m_pad, n, k, key, *, k_pad: int, iters: int):
     satellite *i* — batching (and sharding the sat axis across a device
     mesh) changes which device runs a lane, not what it computes.
     """
-    return jax.vmap(
+    from repro.core.fleet_sharding import map_lanes
+    return map_lanes(jax.vmap(
         lambda m, nn, kk, ke: _dedup_core_body(m, nn, kk, ke,
                                                k_pad=k_pad, iters=iters)
-    )(m_pad, n, k, key)
+    ), mesh)(m_pad, n, k, key)
 
 
-_dedup_finalize_multi = jax.jit(jax.vmap(_dedup_finalize_body))
+@partial(jax.jit, static_argnames=("mesh",))
+def _dedup_finalize_multi(x_pad, cent, nj, mesh=None):
+    """:func:`_dedup_finalize_body` batched over a leading sat axis."""
+    from repro.core.fleet_sharding import map_lanes
+    return map_lanes(jax.vmap(_dedup_finalize_body), mesh)(x_pad, cent, nj)
 
 
 def _buckets_for(n: int, k: int):
@@ -281,10 +287,11 @@ def dedup_from_moments(moments: jnp.ndarray, k: int, key, iters: int = 10,
     ignored). Everything runs on power-of-two padded shapes: one
     compiled program per size bucket serves every workload.
     """
+    from repro.core.fleet_sharding import on_one_device
     n = int(moments.shape[0]) if n is None else int(n)
     n_pad, k_pad = _buckets_for(n, k)
     nj = jnp.int32(n)
-    m_pad = _pad_rows(moments, n, n_pad)
+    m_pad = on_one_device(_pad_rows(moments, n, n_pad))
     x_pad, cent = _dedup_padded_core(m_pad, nj, jnp.int32(k), key,
                                      k_pad=k_pad, iters=iters)
     assign, rep_mask, sizes, rep_clip = _dedup_finalize(x_pad, cent, nj)
@@ -351,10 +358,10 @@ def dedup_multi(parts, iters: int = 10, sharding=None):
         ns_j = xfer.device_constant(ns, sharding=sh)
         ks_j = xfer.device_constant(ks, sharding=sh)
         keys = xfer.device_constant(keys, sharding=sh)
-        x, cent = _dedup_multi_core(m, ns_j, ks_j, keys,
-                                    k_pad=k_pad, iters=iters)
+        x, cent = _dedup_multi_core(m, ns_j, ks_j, keys, k_pad=k_pad,
+                                    iters=iters, mesh=sh.mesh)
         assign, rep_mask, sizes, rep_clip = _dedup_finalize_multi(
-            x, cent, ns_j)
+            x, cent, ns_j, mesh=sh.mesh)
         for i, (slot, _, k, _, n) in enumerate(items):
             out[slot] = DedupResult(assign[i, :n], cent[i, :k],
                                     rep_mask[i, :n], sizes[i, :k],

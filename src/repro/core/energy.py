@@ -299,8 +299,7 @@ class FleetLedger:
 
 
 def max_tiles_within_budget_vec(budget_j, gflops_per_tile: float,
-                                profile: DeviceProfile,
-                                sharding=None, defer: bool = False):
+                                profile: DeviceProfile):
     """Vectorized :func:`max_tiles_within_budget` over stacked budgets.
 
     Quotients are clamped below 2**62 before the integer cast — unlike
@@ -309,53 +308,16 @@ def max_tiles_within_budget_vec(budget_j, gflops_per_tile: float,
     tiles. The clamp exceeds any real tile count, so caps stay
     effectively unbounded (and fleet/oracle-identical) either way.
 
-    ``sharding``: optional on-mesh
-    :class:`~repro.core.fleet_sharding.FleetSharding` — the stacked
-    budget lanes are then placed along the ``sats`` mesh axis and the
-    quotient clamp computed on-device in float64 (IEEE division and the
-    truncating int64 cast are exactly specified, so on-mesh caps are
-    bit-equal to the host computation).
-
-    ``defer=True`` returns a zero-argument resolver instead of the caps
-    array: on-mesh, the cap program is dispatched immediately but the
-    device->host round-trip happens only when the resolver is called —
-    the fleet's ingest-overlap tail dispatches caps right after the
-    aggregation charge and fetches them after the dedup results land, so
-    the round-trip rides behind the dedup wait. Off-mesh the computation
-    is host-side anyway; the resolver just hands back the result.
+    Computed on the host in float64 for every fleet, sharded or not, so
+    the caps are bit-equal to the per-satellite host computation.
     """
     budget_j = np.asarray(budget_j, np.float64)
     if gflops_per_tile <= 0:
         caps = np.zeros(budget_j.shape, np.int64)
-        return (lambda: caps) if defer else caps
-    if sharding is not None and sharding.on_mesh and budget_j.ndim == 1:
-        return _lane_caps_on_mesh(budget_j, gflops_per_tile, profile,
-                                  sharding, defer=defer)
-    q = budget_j / (gflops_per_tile * profile.joules_per_gflop)
-    caps = np.minimum(q, np.float64(2 ** 62)).astype(np.int64)
-    return (lambda: caps) if defer else caps
-
-
-def _lane_caps_on_mesh(budget_j: np.ndarray, gflops_per_tile: float,
-                       profile: DeviceProfile, sharding,
-                       defer: bool = False):
-    """Compute per-lane compute caps with the ledger lanes device-placed
-    along the ``sats`` mesh axis (f64 via a local x64 scope — jax's
-    default f32 downcast would break cap parity with the host op).
-    ``defer=True`` dispatches the program and returns a resolver for the
-    device->host round-trip (the array carries its own int64 dtype, so
-    the fetch needs no x64 scope)."""
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    n = budget_j.shape[0]
-    with enable_x64():
-        lanes = sharding.shard(jnp.asarray(budget_j, jnp.float64))
-        q = lanes / (gflops_per_tile * profile.joules_per_gflop)
-        caps = jnp.minimum(q, jnp.float64(2 ** 62)).astype(jnp.int64)
-    if defer:
-        return lambda: np.asarray(caps)[:n]
-    return np.asarray(caps)[:n]
+    else:
+        q = budget_j / (gflops_per_tile * profile.joules_per_gflop)
+        caps = np.minimum(q, np.float64(2 ** 62)).astype(np.int64)
+    return caps
 
 
 def max_tiles_within_budget(budget_j: float, gflops_per_tile: float,

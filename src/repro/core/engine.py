@@ -84,18 +84,20 @@ _frame_program = partial(jax.jit, static_argnames=(
 
 
 @partial(jax.jit, static_argnames=("tile_size", "sp_size", "gd_size",
-                                   "with_stats"))
+                                   "with_stats", "mesh"))
 def _frame_program_multi(chunks, tile_size: int, sp_size: int, gd_size: int,
-                         with_stats: bool = True):
+                         with_stats: bool = True, mesh=None):
     """The fused frame program vmapped over a stacked chunk axis.
 
     ``chunks`` is (n_chunks, frame_bucket, H, W, C); with the chunk axis
-    placed along a ``sats`` device mesh, each device captures its share
-    of the fleet's frame buckets in parallel. The body is per-sample, so
-    per-chunk outputs are bit-equal to looping :func:`_frame_program`.
+    placed along a ``sats`` device ``mesh``, each device captures its
+    share of the fleet's frame buckets in parallel. The body is
+    per-sample, so per-chunk outputs are bit-equal to looping
+    :func:`_frame_program`.
     """
-    return jax.vmap(lambda imgs: _frame_program_body(
-        imgs, tile_size, sp_size, gd_size, with_stats))(chunks)
+    from repro.core.fleet_sharding import map_lanes
+    return map_lanes(jax.vmap(lambda imgs: _frame_program_body(
+        imgs, tile_size, sp_size, gd_size, with_stats)), mesh)(chunks)
 
 
 def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
@@ -126,7 +128,7 @@ def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
         chunks_arr[:n_chunks] = arr.reshape(n_chunks, frame_bucket, *shape)
         stacked = sh.device_put(jnp.asarray(chunks_arr))
         outs = _frame_program_multi(stacked, tile_size, sp_size, gd_size,
-                                    with_stats)
+                                    with_stats, mesh=sh.mesh)
         return [tuple(o[i] for o in outs) for i in range(n_chunks)]
     return [_frame_program(jnp.asarray(arr[c0:c0 + frame_bucket]),
                            tile_size, sp_size, gd_size, with_stats)

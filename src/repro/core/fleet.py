@@ -141,10 +141,10 @@ class Fleet:
         dispatched BEFORE round k's tail is resolved — so round k's
         device compute runs behind round k+1's dispatch work. All
         device->host syncs (dedup assign/rep gather, the fleet-wide
-        ``roi_std`` copy, counting results, the on-mesh window-cap
-        round-trip) become deferred fetches resolved at the round's
-        Aggregate/recount boundary: the next ``ingest()``, any contact
-        round, ``results()``/``finalize()``/``summary()``, or a clean
+        ``roi_std`` copy, counting results) become deferred fetches
+        resolved at the round's Aggregate/recount boundary: the next
+        ``ingest()``, any contact round,
+        ``results()``/``finalize()``/``summary()``, or a clean
         ``__exit__``. Ledger interaction is double-buffered exactly like
         the recount pipeline's snapshot-at-dispatch: at most one round's
         ledger tail plus one round's counting fetches are ever pending,
@@ -430,14 +430,9 @@ class Fleet:
                 # tail lands before any LATER round's grant.
                 if nops is not None:
                     self.ledger.charge_aggregate(nops)
-                # dispatch the on-mesh cap program now so its round-trip
-                # rides behind the dedup-result wait (remaining is final
-                # for this round: charge_aggregate just landed)
-                caps_resolver = self._dispatch_caps(count_sats)
                 if dedup_fetch is not None:
                     dedup_fetch()  # seg.rep_of writes (no ledger)
-                self._onboard_count_batched(count_sats, segs, defer=True,
-                                            caps_resolver=caps_resolver)
+                self._onboard_count_batched(count_sats, segs, defer=True)
                 for i in sats:
                     m, seg = self.missions[i], segs[i]
                     reports[i].tiles_processed_space = seg.n_processed
@@ -564,29 +559,10 @@ class Fleet:
         self.ledger.charge_aggregate(nops)
         return None
 
-    def _dispatch_caps(self, sats):
-        """Enqueue the uniform-profile on-mesh energy-cap program and
-        return its deferred resolver (``None`` when the fleet has
-        heterogeneous pricing, or nothing to count — the per-satellite
-        fallback in :meth:`_onboard_count_batched` covers those)."""
-        if not sats:
-            return None
-        profiles = {(self.missions[i].gflops_space,
-                     self.missions[i].pcfg.hardware) for i in sats}
-        if len(profiles) != 1:
-            return None
-        (gflops, hw), = profiles
-        return max_tiles_within_budget_vec(self.ledger.remaining * 0.95,
-                                           gflops, hw,
-                                           sharding=self.sharding, defer=True)
-
-    def _onboard_count_batched(self, sats, segs, defer=False,
-                               caps_resolver=None):
+    def _onboard_count_batched(self, sats, segs, defer=False):
         """Mission.OnboardCount semantics, with every satellite's
         energy-capped representative set counted in shared batches.
 
-        ``caps_resolver`` (from :meth:`_dispatch_caps`) supplies the
-        uniform energy caps from an already-in-flight device program.
         With ``defer=True`` the rep selection and compute charge still
         happen eagerly (they feed the ledger and reports), but each
         counting batch's device->host fetch is parked on
@@ -603,10 +579,8 @@ class Fleet:
         caps = None
         if uniform:
             (gflops, hw), = profiles
-            caps = (caps_resolver() if caps_resolver is not None else
-                    max_tiles_within_budget_vec(self.ledger.remaining * 0.95,
-                                                gflops, hw,
-                                                sharding=self.sharding))
+            caps = max_tiles_within_budget_vec(self.ledger.remaining * 0.95,
+                                               gflops, hw)
         process: Dict[int, np.ndarray] = {}
         nproc = np.zeros(self.ledger.n_lanes, np.float64)
         for i in sats:

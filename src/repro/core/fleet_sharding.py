@@ -56,20 +56,54 @@ def sats_mesh(n_devices: Optional[int] = None) -> Optional[Mesh]:
 
     ``None`` uses every visible device. Returns ``None`` (= off-mesh,
     single-device fleet path) when only one device would participate —
-    callers never special-case device counts. On CPU, multiple host
-    devices come from ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    (set before the first jax import).
+    callers never special-case device counts. (The CPU tests get several
+    devices from ``XLA_FLAGS=--xla_force_host_platform_device_count=N``,
+    set before the first jax import.)
     """
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if n > len(devs):
         raise ValueError(
-            f"sats_mesh: {n} devices requested but only {len(devs)} visible "
-            f"(set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            f"before jax initializes for forced host devices)")
+            f"sats_mesh: {n} devices requested but only {len(devs)} "
+            f"{devs[0].platform} device(s) visible")
     if n <= 1:
         return None
     return Mesh(np.asarray(devs[:n]), (SATS_AXIS,))
+
+
+def map_lanes(fn, mesh: Optional[Mesh], replicated: int = 0):
+    """``fn`` (per-lane work over the leading axis of its arguments)
+    run so each device computes its own lanes: under ``shard_map`` on a
+    ``sats`` ``mesh``, as-is off-mesh (``mesh=None``). The first
+    ``replicated`` arguments (e.g. counter weights) go whole to every
+    device.
+
+    The stacked fleet programs call Pallas kernels, and XLA cannot
+    partition a Pallas kernel by itself; ``shard_map`` hands each device
+    its local lanes explicitly. Lanes never couple, so no collective is
+    needed."""
+    if mesh is None:
+        return fn
+
+    def run(*args):
+        specs = tuple(P() if i < replicated else P(SATS_AXIS)
+                      for i in range(len(args)))
+        # check_vma off: Pallas outputs carry no varying-axes type
+        return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                             out_specs=P(SATS_AXIS), check_vma=False)(*args)
+    return run
+
+
+def on_one_device(x):
+    """``x`` committed to one device (the lowest-id device it spans).
+
+    A program that calls a Pallas kernel outside :func:`map_lanes`
+    cannot be partitioned, and an array cut from a mesh-sharded fleet
+    output may span the whole mesh; single-device arrays pass as-is."""
+    devs = x.sharding.device_set
+    if len(devs) == 1:
+        return x
+    return jax.device_put(x, min(devs, key=lambda d: d.id))
 
 
 class FleetSharding:

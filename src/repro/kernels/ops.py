@@ -1,16 +1,13 @@
 """Public kernel entry points with platform dispatch.
 
-Models call these; on TPU (and when shapes are tile-aligned) they route
-to the Pallas kernels, otherwise to the pure-jnp oracle in ref.py — so
-the same model code runs on the CPU dry-run and on real hardware.
-
-Set ``force`` to 'pallas' / 'ref' to override (tests use
-``interpret=True`` through the kernel modules directly as well).
+Models call these; on the TPU they run the Pallas kernels, elsewhere the
+pure-jnp oracles in ref.py — so the same model code runs in the CPU
+tests and dry-run and on the chip. A kernel that fails to lower on the
+TPU fails the run; nothing falls back to the reference there. (Tests
+run the Pallas kernels on the CPU with ``interpret=True``.)
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional
 
 import jax
@@ -23,18 +20,9 @@ from repro.kernels.iou import iou_matrix as _iou_pallas
 from repro.kernels.kmeans_assign import kmeans_assign as _kmeans_pallas
 from repro.kernels.tile_moments import tile_moments as _moments_pallas
 
-_FORCE = os.environ.get("REPRO_KERNELS", "auto")  # auto | pallas | ref
-
 
 def _on_tpu() -> bool:
-    if _FORCE == "pallas":
-        return True
-    if _FORCE == "ref":
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, *, causal: bool = False):

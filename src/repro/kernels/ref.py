@@ -59,12 +59,17 @@ def tile_moments(tiles):
 
 
 def kmeans_assign(x, centroids):
-    """x: (N, D), centroids: (K, D) -> (assign (N,) int32, sqdist (N,) f32)."""
+    """x: (N, D), centroids: (K, D) -> (assign (N,) int32, sqdist (N,) f32).
+
+    The cross term is a float32 matmul (``HIGHEST``), as in the Pallas
+    kernel: at the TPU's default matmul precision its inputs would round
+    to bfloat16, and nearest-centroid assignments could flip.
+    """
     xf = x.astype(jnp.float32)
     cf = centroids.astype(jnp.float32)
     d2 = (
         jnp.sum(xf * xf, -1, keepdims=True)
-        - 2.0 * xf @ cf.T
+        - 2.0 * jnp.matmul(xf, cf.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(cf * cf, -1)[None, :]
     )
     a = jnp.argmin(d2, axis=-1).astype(jnp.int32)

@@ -56,11 +56,8 @@ def run_cell(arch: str, shape: str, mesh_name: str, opts=None,
     t0 = time.time()
     plan = build_cell(arch, shape, mesh, **opts)
     # set_mesh so in-model with_sharding_constraint(PartitionSpec) calls
-    # resolve; older jax spells it use_mesh, and older still only has the
-    # `with mesh:` context manager (same ambient-mesh semantics there)
-    set_mesh = (getattr(jax.sharding, "set_mesh", None)
-                or getattr(jax.sharding, "use_mesh", None))
-    with (set_mesh(mesh) if set_mesh else mesh):
+    # resolve
+    with jax.sharding.set_mesh(mesh):
         jfn = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                       out_shardings=plan.out_shardings,
                       donate_argnums=plan.donate_argnums)

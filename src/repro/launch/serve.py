@@ -21,6 +21,7 @@ from repro.core.mission import Mission
 from repro.core.pipeline import PipelineConfig
 from repro.core.policies import available_policies
 from repro.data.synthetic import DATASETS, SceneSpec, make_scene, revisit_frames
+from repro.launch import compile_cache
 
 CACHE = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                      "artifacts", "counters")
@@ -78,6 +79,7 @@ def main():
     ap.add_argument("--bandwidth", type=float, default=50.0)
     ap.add_argument("--retrain", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = (DATASETS[args.dataset] if args.dataset in DATASETS
             else SceneSpec("mini", 512, (20, 30), (10, 24), cloud_fraction=0.2))

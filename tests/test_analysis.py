@@ -160,8 +160,6 @@ def test_jitguard_counts_fresh_compile_and_cached_silence():
     x = jnp.arange(7, dtype=jnp.float32)
     with JitGuard("cold") as cold:
         fn(x).block_until_ready()
-    if not cold.supported:
-        pytest.skip("no compilation-count source on this jax build")
     assert cold.compilations >= 1
     with JitGuard("warm") as warm:
         fn(x).block_until_ready()
@@ -175,8 +173,6 @@ def test_jitguard_assert_raises_on_recompile():
     with JitGuard("churn") as g:
         # a fresh shape forces a new XLA program
         fn(jnp.arange(6, dtype=jnp.float32)).block_until_ready()
-    if not g.supported:
-        pytest.skip("no compilation-count source on this jax build")
     with pytest.raises(AssertionError, match="churn"):
         g.assert_steady_state("shape churn")
 
@@ -201,7 +197,5 @@ def test_jitguard_fleet_rounds_reach_steady_state(counters):
     with JitGuard("fleet steady state") as g:
         round_(fleet)
         round_(fleet)
-    if not g.supported:
-        pytest.skip("no compilation-count source on this jax build")
     g.assert_steady_state("steady-state ingest rounds")
     fleet.finalize()

@@ -424,7 +424,9 @@ def test_fleet_sharded_uneven_lane_padding(counters):
         for key in ("n_devices", "ingest_s", "tiles_per_s",
                     "tiles_per_s_per_sat", "contact_s", "windows_per_s",
                     "bytes_downlinked_per_s", "recount_s", "recount_wait_s",
-                    "recount_hidden_frac"):
+                    "recount_hidden_frac", "ingest_dispatch_s",
+                    "device_compute_s", "host_fetch_s",
+                    "ingest_hidden_frac"):
             s.pop(key)
     assert ss == s1
 
@@ -440,6 +442,40 @@ def test_fleet_sharded_matches_oracle_missions(scenario, counters):
     want, _ = run_scenario(space, ground, pcfg, scenario, fleet=False)
     for i, (a, b) in enumerate(zip(got, want)):
         _assert_same(a, b, f"sharded-vs-oracle sat{i}")
+
+
+@requires_mesh
+@pytest.mark.parametrize("strict", [False, True])
+def test_fleet_sharded_kernel_programs_get_one_device(counters, monkeypatch,
+                                                      strict):
+    """On the TPU a program that calls a Pallas kernel outside
+    ``map_lanes`` cannot be partitioned, so every such program of the
+    sharded fleet (capture, sequential dedup, chunk counting) must be
+    handed single-device operands — the CPU stand-in for that chip
+    error. One scene size, so capture takes the sharded stacked path."""
+    import repro.core.cascade as cascade
+    import repro.core.engine as engine
+    spanning = []
+
+    def guard(name, fn):
+        def run(*args, **kw):
+            spanning.extend(
+                name for x in jax.tree.leaves((args, kw))
+                if hasattr(x, "sharding") and len(x.sharding.device_set) > 1)
+            return fn(*args, **kw)
+        return run
+
+    for mod, name in ((cascade, "count_tiles"), (engine, "_frame_program"),
+                      (dd, "_dedup_padded_core"), (dd, "_dedup_finalize")):
+        monkeypatch.setattr(mod, name, guard(name, getattr(mod, name)))
+    space, ground = counters
+    sc = generate_scenario(FleetScenarioSpec(
+        n_sats=4, n_rounds=2, frames_per_pass=2,
+        stations=(GroundStation("gs0"),), scene_mix=(SCENE_B,), seed=3))
+    pcfg = PipelineConfig(method="targetfuse", score_thresh=0.25)
+    run_scenario(space, ground, pcfg, sc, fleet=True, mesh=sats_mesh(4),
+                 strict_parity=strict)
+    assert not spanning, f"mesh-spanning operands reached {set(spanning)}"
 
 
 @requires_mesh

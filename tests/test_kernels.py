@@ -79,7 +79,8 @@ def test_kmeans_assign(n, d, k):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n,h,w,c", [(16, 32, 32, 3), (100, 16, 16, 3),
-                                     (7, 64, 64, 1), (130, 8, 8, 4)])
+                                     (7, 64, 64, 1), (130, 8, 8, 4),
+                                     (5, 416, 416, 3)])
 def test_tile_moments(n, h, w, c):
     t = jax.random.uniform(_key(0), (n, h, w, c), jnp.float32)
     m1 = tile_moments(t, interpret=True)
