@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import DetectorConfig
-from repro.core import tiling, xfer
+from repro.core import obs, tiling, xfer
 from repro.core.dedup import bucket_size
 from repro.models import detector
 from repro.optim.adamw import adamw
@@ -82,35 +82,48 @@ def _count_forward(params, cfg, t, batch: int, score_thresh, nms_iou,
     from repro.core.fleet_sharding import ctx
     sh = ctx(sharding)
     pad = -t.shape[0] % batch
-    if pad:
-        t = jnp.concatenate([t, jnp.zeros((pad, *t.shape[1:]), t.dtype)])
-    t = t.reshape(-1, batch, *t.shape[1:])
+    with obs.span("count.pad"):
+        if pad:
+            t = jnp.concatenate([t, jnp.zeros((pad, *t.shape[1:]), t.dtype)])
+        t = t.reshape(-1, batch, *t.shape[1:])
     n_chunks = t.shape[0]
     if sh.on_mesh and n_chunks > 1:
         # pad the chunk axis to a power-of-two bucket x device multiple
         # (zero chunks are inert): the stacked forward compiles per
         # chunk count, and workloads present many distinct counts
         n_stack = sh.pad(bucket_size(n_chunks, 1))
-        if n_stack != n_chunks:
-            t = jnp.concatenate(
-                [t, jnp.zeros((n_stack - n_chunks, *t.shape[1:]), t.dtype)])
-        c, f = _count_tiles_chunks(params, cfg, sh.device_put(t),
-                                   score_thresh, nms_iou, mesh=sh.mesh)
-        out = jnp.stack([c[:n_chunks].reshape(-1),
-                         f[:n_chunks].reshape(-1)])
-        # analysis: waive(host-sync): the designated single host copy of a
-        # counting batch; callers passing defer=True skip even this one
-        return out if defer else np.asarray(out)
+        obs.count("count.rows_computed", n_stack * batch)
+        with obs.span("count.pad"):
+            if n_stack != n_chunks:
+                t = jnp.concatenate(
+                    [t, jnp.zeros((n_stack - n_chunks, *t.shape[1:]),
+                                  t.dtype)])
+        with obs.span("count.program"):
+            c, f = _count_tiles_chunks(params, cfg, sh.device_put(t),
+                                       score_thresh, nms_iou, mesh=sh.mesh)
+            out = jnp.stack([c[:n_chunks].reshape(-1),
+                             f[:n_chunks].reshape(-1)])
+        if defer:
+            return out
+        with obs.span("count.fetch"):
+            # analysis: waive(host-sync): the designated single host copy
+            # of a counting batch; callers passing defer=True skip it
+            return np.asarray(out)
     from repro.core.fleet_sharding import on_one_device
-    t = on_one_device(t)
-    outs_c, outs_f = [], []
-    for i in range(n_chunks):
-        c, f = count_tiles(params, cfg, t[i], score_thresh, nms_iou)
-        outs_c.append(c)
-        outs_f.append(f)
-    out = jnp.stack([jnp.concatenate(outs_c), jnp.concatenate(outs_f)])
-    # analysis: waive(host-sync): same designated copy, small-batch path
-    return out if defer else np.asarray(out)
+    obs.count("count.rows_computed", n_chunks * batch)
+    with obs.span("count.program"):
+        t = on_one_device(t)
+        outs_c, outs_f = [], []
+        for i in range(n_chunks):
+            c, f = count_tiles(params, cfg, t[i], score_thresh, nms_iou)
+            outs_c.append(c)
+            outs_f.append(f)
+        out = jnp.stack([jnp.concatenate(outs_c), jnp.concatenate(outs_f)])
+    if defer:
+        return out
+    with obs.span("count.fetch"):
+        # analysis: waive(host-sync): same designated copy, small-batch path
+        return np.asarray(out)
 
 
 def count_tiles_batched(params, cfg, tiles, batch: int = 64, score_thresh=0.3,
@@ -134,13 +147,16 @@ def count_tiles_batched(params, cfg, tiles, batch: int = 64, score_thresh=0.3,
     if n == 0:
         return np.zeros((0,), np.float32), np.zeros((0,), np.float32)
     batch = _tier_batch(n, batch)
+    obs.count("count.rows_real", n)
     if idx is not None:
-        n_pad = -(-n // batch) * batch
-        idx_pad = np.zeros(n_pad, np.int64)
-        idx_pad[:n] = np.asarray(idx)
-        # content-keyed upload cache: repeated-shape rounds gather with
-        # the same index vectors, so steady state issues zero transfers
-        t = jnp.asarray(tiles)[xfer.device_constant(idx_pad)]
+        with obs.span("count.gather"):
+            n_pad = -(-n // batch) * batch
+            idx_pad = np.zeros(n_pad, np.int64)
+            idx_pad[:n] = np.asarray(idx)
+            # content-keyed upload cache: repeated-shape rounds gather
+            # with the same index vectors, so steady state makes no
+            # transfers
+            t = jnp.asarray(tiles)[xfer.device_constant(idx_pad)]
     else:
         t = jnp.asarray(tiles)
     # padding trimmed host-side, so every device op ran at a bucketed shape
@@ -187,6 +203,7 @@ def count_tiles_multi(params, cfg, parts, batch: int = 64, score_thresh=0.3,
     if total == 0:
         out = [empty for _ in parts]
         return (lambda: out) if defer else out
+    obs.count("count.rows_real", total)
     gathered, spans, off = [], [], 0
     for (tiles, idx), k in zip(parts, sizes):
         if not k:

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import tiling
+from repro.core import obs, tiling
 from repro.core.dedup import bucket_size
 from repro.kernels import ops as kops
 
@@ -115,24 +115,39 @@ def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
     from repro.core.fleet_sharding import ctx
     sh = ctx(sharding)
     nb = -(-len(imgs) // frame_bucket) * frame_bucket
-    arr = np.zeros((nb, *shape), np.float32)
-    for j, img in enumerate(imgs):
-        arr[j] = img
+    with obs.span("capture.fill"):
+        arr = np.zeros((nb, *shape), np.float32)
+        for j, img in enumerate(imgs):
+            arr[j] = img
+    obs.count("capture.frames_real", len(imgs))
     n_chunks = nb // frame_bucket
     if sh.on_mesh and n_chunks > 1:
         # pad the chunk axis to a power-of-two bucket x device multiple:
         # chunk counts vary per round, and the stacked program compiles
         # per chunk count — bucketing bounds the program count
         n_stack = sh.pad(bucket_size(n_chunks, 1))
-        chunks_arr = np.zeros((n_stack, frame_bucket, *shape), np.float32)
-        chunks_arr[:n_chunks] = arr.reshape(n_chunks, frame_bucket, *shape)
-        stacked = sh.device_put(jnp.asarray(chunks_arr))
-        outs = _frame_program_multi(stacked, tile_size, sp_size, gd_size,
-                                    with_stats, mesh=sh.mesh)
+        with obs.span("capture.fill"):
+            chunks_arr = np.zeros((n_stack, frame_bucket, *shape), np.float32)
+            chunks_arr[:n_chunks] = arr.reshape(n_chunks, frame_bucket,
+                                                *shape)
+        obs.count("capture.frames_computed", n_stack * frame_bucket)
+        obs.count("capture.h2d_bytes", chunks_arr.nbytes)
+        with obs.span("capture.to_device"):
+            stacked = sh.device_put(jnp.asarray(chunks_arr))
+        with obs.span("capture.program"):
+            outs = _frame_program_multi(stacked, tile_size, sp_size, gd_size,
+                                        with_stats, mesh=sh.mesh)
         return [tuple(o[i] for o in outs) for i in range(n_chunks)]
-    return [_frame_program(jnp.asarray(arr[c0:c0 + frame_bucket]),
-                           tile_size, sp_size, gd_size, with_stats)
-            for c0 in range(0, nb, frame_bucket)]
+    obs.count("capture.frames_computed", nb)
+    obs.count("capture.h2d_bytes", arr.nbytes)
+    out = []
+    for c0 in range(0, nb, frame_bucket):
+        with obs.span("capture.to_device"):
+            chunk = jnp.asarray(arr[c0:c0 + frame_bucket])
+        with obs.span("capture.program"):
+            out.append(_frame_program(chunk, tile_size, sp_size, gd_size,
+                                      with_stats))
+    return out
 
 
 def _per_frame_pieces(frames, tile_size: int, sp_size: int, gd_size: int,
@@ -176,37 +191,38 @@ def _assemble(parts, frames, tile_size: int, roi_std=None,
     own round boundary, or never (policies that don't use ROI)."""
     from repro.data.synthetic import tile_counts
 
-    if n is None:
-        n = sum(p[0].shape[0] for p in parts)
+    with obs.span("capture.assemble"):
+        if n is None:
+            n = sum(p[0].shape[0] for p in parts)
 
-    def cat(j):
-        return parts[0][j] if len(parts) == 1 else jnp.concatenate(
-            [p[j] for p in parts])
+        def cat(j):
+            return parts[0][j] if len(parts) == 1 else jnp.concatenate(
+                [p[j] for p in parts])
 
-    n_pad = bucket_size(n)
+        n_pad = bucket_size(n)
 
-    def pad(a):
-        if a.shape[0] == n_pad:
-            return a
-        if a.shape[0] > n_pad:
-            return a[:n_pad]
-        return jnp.concatenate(
-            [a, jnp.zeros((n_pad - a.shape[0], *a.shape[1:]), a.dtype)])
+        def pad(a):
+            if a.shape[0] == n_pad:
+                return a
+            if a.shape[0] > n_pad:
+                return a[:n_pad]
+            return jnp.concatenate(
+                [a, jnp.zeros((n_pad - a.shape[0], *a.shape[1:]), a.dtype)])
 
-    with_stats = len(parts[0]) == 4
-    tiles_sp = pad(cat(0))
-    tiles_gd = pad(cat(1))
-    moments = pad(cat(2)) if with_stats else None
-    if roi_std is None and with_stats:
-        rs = pad(cat(3))[:n]
-        # analysis: waive(host-sync): the per-workload roi_std copy is the
-        # designed transfer point; defer_stats keeps it lazy on device
-        roi_std = rs if defer_stats else np.asarray(rs)
-    true = np.concatenate([
-        tile_counts(boxes, np.shape(img)[0], tile_size)
-        for img, boxes, _ in frames
-    ]).astype(np.float64)
-    return PreparedFrames(tiles_sp, tiles_gd, moments, roi_std, true, n)
+        with_stats = len(parts[0]) == 4
+        tiles_sp = pad(cat(0))
+        tiles_gd = pad(cat(1))
+        moments = pad(cat(2)) if with_stats else None
+        if roi_std is None and with_stats:
+            rs = pad(cat(3))[:n]
+            # analysis: waive(host-sync): the per-workload roi_std copy is the
+            # designed transfer point; defer_stats keeps it lazy on device
+            roi_std = rs if defer_stats else np.asarray(rs)
+        true = np.concatenate([
+            tile_counts(boxes, np.shape(img)[0], tile_size)
+            for img, boxes, _ in frames
+        ]).astype(np.float64)
+        return PreparedFrames(tiles_sp, tiles_gd, moments, roi_std, true, n)
 
 
 def _empty_prepared(sp_size: int, gd_size: int,

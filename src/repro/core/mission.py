@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.core.dedup as dd
-from repro.core import engine
+from repro.core import engine, obs
 from repro.core.cascade import count_tiles_batched, count_tiles_batched_ref
 from repro.core.energy import (ByteLedger, EnergyLedger, detector_gflops,
                                max_tiles_within_budget)
@@ -251,16 +251,20 @@ class Dedup(Stage):
             # bucketed gather of the fused program's moments: pad the index
             # vector so the gather (and the whole dedup) is shape-stable
             n_act = len(idx_active)
-            idx_pad = np.zeros(dd.dedup_pad_size(n_act), np.int64)
-            idx_pad[:n_act] = idx_active
-            res = dd.dedup_from_moments(seg.prep.moments[jnp.asarray(idx_pad)],
-                                        k, jax.random.PRNGKey(pcfg.seed),
-                                        n=n_act)
+            with obs.span("dedup.gather"):
+                idx_pad = np.zeros(dd.dedup_pad_size(n_act), np.int64)
+                idx_pad[:n_act] = idx_active
+                moments = seg.prep.moments[jnp.asarray(idx_pad)]
+            with obs.span("dedup.program"):
+                res = dd.dedup_from_moments(moments, k,
+                                            jax.random.PRNGKey(pcfg.seed),
+                                            n=n_act)
         else:
             res = dd.dedup(jnp.asarray(seg.tiles_sp[idx_active]), k,
                            jax.random.PRNGKey(pcfg.seed))
-        assign = np.asarray(res.assign)
-        rep_local = np.asarray(res.rep_idx)
+        with obs.span("dedup.fetch"):
+            assign = np.asarray(res.assign)
+            rep_local = np.asarray(res.rep_idx)
         seg.rep_of[idx_active] = idx_active[rep_local[assign]]
         mission.ledger.charge_aggregate(len(idx_active))
 
@@ -285,7 +289,8 @@ class OnboardCount(Stage):
         counts_sp = np.zeros(seg.n)
         conf = np.full(seg.n, -1.0)
         if seg.n_processed:
-            c, f = mission._count(mission.space, seg.tiles_sp, process)
+            with obs.span("count.space"):
+                c, f = mission._count(mission.space, seg.tiles_sp, process)
             counts_sp[process] = c
             conf[process] = f
         seg.counts_sp = counts_sp[seg.rep_of]
@@ -346,7 +351,8 @@ class GroundRecount(Stage):
         counts_gd = np.zeros(seg.n)
         down = seg.selection.downlink
         if len(down):
-            c, _ = mission._count(mission.ground, seg.tiles_gd, down)
+            with obs.span("count.ground"):
+                c, _ = mission._count(mission.ground, seg.tiles_gd, down)
             counts_gd[down] = c
         seg.counts_gd = counts_gd[seg.rep_of]
 
@@ -453,8 +459,10 @@ class Mission:
         self._finalized = False
         seg = Segment(frames=list(frames),
                       energy_grant_override=energy_budget_j)
-        for stage in self.ingest_stages:
-            stage.run(self, seg)
+        with obs.span("mission.ingest"):
+            for stage in self.ingest_stages:
+                with obs.span("stage." + stage.name):
+                    stage.run(self, seg)
         self._segments.append(seg)
         self._pending.append(seg)
         return IngestReport(
@@ -475,11 +483,13 @@ class Mission:
         flushes anything nor inflates the byte-budget accounting."""
         if self._window_is_noop():
             return self._drained_window_report()
-        segs, window = self._open_window(budget_bytes)
-        for seg in segs:
-            for stage in self.contact_stages:
-                stage.run(self, seg, window)
-        return self._window_report(window, segs)
+        with obs.span("mission.contact"):
+            segs, window = self._open_window(budget_bytes)
+            for seg in segs:
+                for stage in self.contact_stages:
+                    with obs.span("stage." + stage.name):
+                        stage.run(self, seg, window)
+            return self._window_report(window, segs)
 
     # window protocol pieces, shared with the fleet engine's batched
     # contact rounds so the drain/accounting rules live in ONE place
@@ -542,10 +552,11 @@ class Mission:
         Idempotent: repeated calls (and :meth:`contact_window` calls in
         between) are no-ops until a new :meth:`ingest` resumes the
         stream."""
-        if self._pending:
-            self.contact_window(0.0)
-        self._finalized = True
-        return self.result()
+        with obs.span("mission.finalize"):
+            if self._pending:
+                self.contact_window(0.0)
+            self._finalized = True
+            return self.result()
 
     def result(self) -> PipelineResult:
         """Aggregate over every segment that has been through a contact

@@ -12,7 +12,9 @@ never donated, never mutated), they can be cached by content —
 issues ZERO transfers for them after its first round.
 
 The counters are the honest ledger the bench gates on (count-based, not
-timing-based): ``transfer_stats()['device_puts']`` counts real
+timing-based), kept as :mod:`repro.core.obs` counters
+(``xfer.device_puts``, ``xfer.cache_reuses``):
+``transfer_stats()['device_puts']`` counts real
 host->device placements issued through this module and through
 :meth:`repro.core.fleet_sharding.FleetSharding.device_put`;
 ``cache_reuses`` counts uploads avoided. ``tests/test_fleet.py`` and
@@ -29,6 +31,8 @@ import threading
 
 import numpy as np
 
+from repro.core import obs
+
 # cache only small control-plane arrays: index vectors, lane counts, key
 # stacks. Data arrays (tiles, frames, moments) are content-unique per
 # round and would only churn the dict.
@@ -37,27 +41,25 @@ _MAX_ENTRIES = 4096
 
 _lock = threading.Lock()
 _cache: dict = {}
-_stats = {"device_puts": 0, "cache_reuses": 0}
+_COUNTERS = {"device_puts": "xfer.device_puts",
+             "cache_reuses": "xfer.cache_reuses"}
 
 
 def record_transfer(n: int = 1) -> None:
     """Count ``n`` real host->device placements (called by every path
     that issues one: this module's misses and
     :meth:`FleetSharding.device_put`)."""
-    with _lock:
-        _stats["device_puts"] += n
+    obs.count(_COUNTERS["device_puts"], n)
 
 
 def transfer_stats() -> dict:
     """Snapshot of the transfer counters (copies; safe to diff)."""
-    with _lock:
-        return dict(_stats)
+    c = obs.counters()
+    return {k: c.get(name, 0) for k, name in _COUNTERS.items()}
 
 
 def reset_transfer_stats() -> None:
-    with _lock:
-        _stats["device_puts"] = 0
-        _stats["cache_reuses"] = 0
+    obs.reset(*_COUNTERS.values())
 
 
 def clear_cache() -> None:
@@ -102,8 +104,7 @@ def device_constant(arr, sharding=None):
     with _lock:
         hit = _cache.get(key)
     if hit is not None:
-        with _lock:
-            _stats["cache_reuses"] += 1
+        obs.count(_COUNTERS["cache_reuses"])
         return hit
     dev = _put(arr, sharding, on_mesh)
     with _lock:
