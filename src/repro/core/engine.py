@@ -55,8 +55,8 @@ class PreparedFrames:
     n: int                  # real tile count (rows [n:] are padding)
 
 
-def _frame_program_body(imgs, tile_size: int, sp_size: int, gd_size: int,
-                        with_stats: bool = True):
+def _tile_batch(imgs, tile_size: int, sp_size: int, gd_size: int,
+                with_stats: bool = True):
     """(B, H, W, C) frames -> (tiles_sp, tiles_gd[, moments, roi_std]).
 
     Fused tile -> resize(space) -> resize(ground) -> tile_moments in one
@@ -79,6 +79,17 @@ def _frame_program_body(imgs, tile_size: int, sp_size: int, gd_size: int,
     return tiles_sp, tiles_gd, moments, roi_std
 
 
+def _frame_program_body(frames, tile_size: int, sp_size: int, gd_size: int,
+                        with_stats: bool = True):
+    """A bucket of separate (H, W, C) device frames -> :func:`_tile_batch`
+    of their stack. The stack runs inside the program, where it fuses
+    with the tiling; the bucket's length is fixed, so the program still
+    compiles once per frame shape. (Device traces find the capture
+    program by this function's name.)"""
+    return _tile_batch(jnp.stack(frames), tile_size, sp_size, gd_size,
+                       with_stats)
+
+
 _frame_program = partial(jax.jit, static_argnames=(
     "tile_size", "sp_size", "gd_size", "with_stats"))(_frame_program_body)
 
@@ -96,29 +107,33 @@ def _frame_program_multi(chunks, tile_size: int, sp_size: int, gd_size: int,
     :func:`_frame_program`.
     """
     from repro.core.fleet_sharding import map_lanes
-    return map_lanes(jax.vmap(lambda imgs: _frame_program_body(
+    return map_lanes(jax.vmap(lambda imgs: _tile_batch(
         imgs, tile_size, sp_size, gd_size, with_stats)), mesh)(chunks)
 
 
 def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
                      frame_bucket: int, sharding=None,
                      with_stats: bool = True):
-    """Zero-pad a same-resolution image list to whole ``frame_bucket``s
-    and run the fused program chunk by chunk (the single definition of
-    bucket rounding/fill, shared by every capture entry point).
+    """Run the fused program over a same-resolution image list in whole
+    ``frame_bucket``s, the last one zero-padded (the single definition of
+    bucket rounding, shared by every capture entry point).
+
+    Off-mesh, each real frame is placed on the device on its own and pad
+    frames are device zeros: nothing is staged on the host, and a frame
+    that is already a device array never leaves the device. Frames are
+    read in place, and the copy to the device may still be in flight
+    when this returns, so a caller must not mutate a frame array until
+    the pass it was ingested in has its results.
 
     With an on-mesh :class:`~repro.core.fleet_sharding.FleetSharding`,
-    the chunks are stacked, lane-padded to a device multiple, and run as
-    ONE sharded :func:`_frame_program_multi` call — capture parallelizes
-    across the mesh instead of queueing per-chunk on one device.
+    the chunks are stacked in one host array, lane-padded to a device
+    multiple, and run as ONE sharded :func:`_frame_program_multi` call —
+    capture parallelizes across the mesh instead of queueing per-chunk on
+    one device.
     """
     from repro.core.fleet_sharding import ctx
     sh = ctx(sharding)
     nb = -(-len(imgs) // frame_bucket) * frame_bucket
-    with obs.span("capture.fill"):
-        arr = np.zeros((nb, *shape), np.float32)
-        for j, img in enumerate(imgs):
-            arr[j] = img
     obs.count("capture.frames_real", len(imgs))
     n_chunks = nb // frame_bucket
     if sh.on_mesh and n_chunks > 1:
@@ -128,8 +143,10 @@ def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
         n_stack = sh.pad(bucket_size(n_chunks, 1))
         with obs.span("capture.fill"):
             chunks_arr = np.zeros((n_stack, frame_bucket, *shape), np.float32)
-            chunks_arr[:n_chunks] = arr.reshape(n_chunks, frame_bucket,
-                                                *shape)
+            flat = chunks_arr.reshape(-1, *shape)
+            for j, img in enumerate(imgs):
+                flat[j] = img
+        obs.count("capture.frames_staged", len(imgs))
         obs.count("capture.frames_computed", n_stack * frame_bucket)
         obs.count("capture.h2d_bytes", chunks_arr.nbytes)
         with obs.span("capture.to_device"):
@@ -139,14 +156,20 @@ def _bucketed_chunks(imgs, shape, tile_size: int, sp_size: int, gd_size: int,
                                         with_stats, mesh=sh.mesh)
         return [tuple(o[i] for o in outs) for i in range(n_chunks)]
     obs.count("capture.frames_computed", nb)
-    obs.count("capture.h2d_bytes", arr.nbytes)
+    n_host = sum(not isinstance(img, jax.Array) for img in imgs)
+    obs.count("capture.h2d_bytes", n_host * 4 * int(np.prod(shape)))
     out = []
     for c0 in range(0, nb, frame_bucket):
+        with obs.span("capture.fill"):
+            bucket = list(imgs[c0:c0 + frame_bucket])
         with obs.span("capture.to_device"):
-            chunk = jnp.asarray(arr[c0:c0 + frame_bucket])
+            frames = [jnp.asarray(img, jnp.float32) for img in bucket]
+            n_pad = frame_bucket - len(frames)  # only the last bucket pads
+            if n_pad:
+                frames += [jnp.zeros(shape, jnp.float32)] * n_pad
         with obs.span("capture.program"):
-            out.append(_frame_program(chunk, tile_size, sp_size, gd_size,
-                                      with_stats))
+            out.append(_frame_program(tuple(frames), tile_size, sp_size,
+                                      gd_size, with_stats))
     return out
 
 

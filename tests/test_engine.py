@@ -97,6 +97,74 @@ def test_prepared_frames_groups_mixed_resolutions():
     np.testing.assert_array_equal(prep.true, np.concatenate(true))
 
 
+def _revisits(spec, n, seed):
+    rng = np.random.default_rng(seed)
+    img, b, c = make_scene(rng, spec)
+    return revisit_frames(rng, img, b, c, n)
+
+
+def _on_stacked_host_batches(frames, bucket=engine.FRAME_BUCKET):
+    """The fused program's body run per resolution on explicitly stacked,
+    zero-padded host batches of ``bucket`` frames; each frame's rows, in
+    input order."""
+    prog = jax.jit(engine._tile_batch, static_argnums=(1, 2, 3))
+    groups = {}
+    for i, (img, _, _) in enumerate(frames):
+        groups.setdefault(img.shape, []).append(i)
+    pieces = [None] * len(frames)
+    for shape, idxs in groups.items():
+        arr = np.zeros((-(-len(idxs) // bucket) * bucket, *shape), np.float32)
+        for j, i in enumerate(idxs):
+            arr[j] = frames[i][0]
+        for c0 in range(0, len(arr), bucket):
+            outs = [np.asarray(o) for o in
+                    prog(jnp.asarray(arr[c0:c0 + bucket]), 128, 64, 48)]
+            per = outs[0].shape[0] // bucket
+            for j, i in enumerate(idxs[c0:c0 + bucket]):
+                pieces[i] = [o[j * per:(j + 1) * per] for o in outs]
+    return [np.concatenate([p[k] for p in pieces]) for k in range(4)]
+
+
+_SMALL = SceneSpec("s", 256, (4, 8), (10, 24), cloud_fraction=0.0)
+_WORKLOADS = {
+    "3 frames": lambda: _revisits(SPEC, 3, 7),
+    "4 frames": lambda: _revisits(SPEC, 4, 8),
+    "8 frames": lambda: _revisits(SPEC, 8, 9),
+    "mixed resolutions": lambda: (_revisits(SPEC, 2, 10)
+                                  + _revisits(_SMALL, 1, 11)
+                                  + _revisits(SPEC, 1, 12)),
+}
+
+
+@pytest.mark.parametrize("workload", list(_WORKLOADS))
+def test_prepared_frames_bit_equal_to_stacked_host_batches(workload):
+    """Frames placed on the device one by one (pad frames made there) give
+    exactly the fused program's output on a stacked, zero-padded host
+    batch; rows past ``n`` are exactly zero."""
+    frames = _WORKLOADS[workload]()
+    prep = engine.prepare_frames(frames, 128, 64, 48)
+    sp, gd, mom, rs = _on_stacked_host_batches(frames)
+    assert prep.n == sp.shape[0]
+    for got, want in ((prep.tiles_sp, sp), (prep.tiles_gd, gd),
+                      (prep.moments, mom)):
+        np.testing.assert_array_equal(np.asarray(got)[:prep.n], want)
+    np.testing.assert_array_equal(prep.roi_std, rs)
+    for tiles in (prep.tiles_sp, prep.tiles_gd):
+        assert not np.asarray(tiles)[prep.n:].any()
+
+
+@pytest.mark.parametrize("workload", ["3 frames", "mixed resolutions"])
+def test_device_frames_prepare_like_host_frames(workload):
+    frames = _WORKLOADS[workload]()
+    host = engine.prepare_frames(frames, 128, 64, 48)
+    dev = engine.prepare_frames([(jnp.asarray(img), b, c)
+                                 for img, b, c in frames], 128, 64, 48)
+    assert dev.n == host.n
+    for k in ("tiles_sp", "tiles_gd", "moments", "roi_std", "true"):
+        np.testing.assert_array_equal(np.asarray(getattr(dev, k)),
+                                      np.asarray(getattr(host, k)))
+
+
 # ---------------------------------------------------------------------------
 # component equivalence
 # ---------------------------------------------------------------------------

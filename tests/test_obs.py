@@ -1,8 +1,8 @@
 """Tests for repro.core.obs — the program's span and counter recorder:
 the null context when off, nesting and parents per thread, counter
 snapshots, compile attribution, the span tree of a tiny Mission, the
-count batches' padding counters, and the transfer counters folded in
-from repro.core.xfer."""
+capture's staging and transfer counters, the count batches' padding
+counters, and the transfer counters folded in from repro.core.xfer."""
 import os
 import sys
 import threading
@@ -233,7 +233,40 @@ def test_mission_counters_match_the_batch_arithmetic(tiny_mission):
     nb = -(-len(frames) // 4) * 4  # engine.FRAME_BUCKET
     assert d["capture.frames_real"] == len(frames)
     assert d["capture.frames_computed"] == nb
-    assert d["capture.h2d_bytes"] == nb * frames[0][0].size * 4
+    # the real frames cross one by one; pad frames are made on the device
+    assert d["capture.h2d_bytes"] == len(frames) * frames[0][0].size * 4
+    assert d.get("capture.frames_staged", 0) == 0
+
+
+@pytest.mark.parametrize("where", ["host", "device", "mesh"])
+def test_capture_stages_frames_only_on_the_mesh(where):
+    """Off-mesh, frames go to the device without a host staging buffer
+    (device frames cross nothing); the on-mesh path, here on a one-device
+    ``sats`` mesh, still stacks its frames on the host."""
+    from jax.sharding import Mesh
+
+    from repro.core import engine
+    from repro.core.fleet_sharding import SATS_AXIS, FleetSharding
+
+    rng = np.random.default_rng(5)
+    imgs = [rng.random((256, 256, 3), dtype=np.float32) for _ in range(5)]
+    if where == "device":
+        imgs = [jnp.asarray(img) for img in imgs]
+    frames = [(img, np.zeros((0, 4), np.float32), np.zeros(0, np.int32))
+              for img in imgs]
+    sh = (FleetSharding(Mesh(np.asarray(jax.devices()[:1]), (SATS_AXIS,)))
+          if where == "mesh" else None)
+    before = obs.counters()
+    engine.prepare_frames_multi([frames[:2], frames[2:]], 128, 64, 48,
+                                sharding=sh)
+    d = _delta(obs.counters(), before)
+    frame_bytes = imgs[0].size * 4
+    assert d["capture.frames_real"] == 5
+    assert d["capture.frames_computed"] == 8
+    staged, crossed = {"host": (0, 5 * frame_bytes), "device": (0, 0),
+                       "mesh": (5, 8 * frame_bytes)}[where]
+    assert d.get("capture.frames_staged", 0) == staged
+    assert d.get("capture.h2d_bytes", 0) == crossed
 
 
 def test_count_multi_counts_part_tier_and_chunk_padding():
