@@ -5,6 +5,8 @@ A cell names a configuration and a traffic mix; the harness finds
     bench/configs/<config>.json      the configuration as it is run
     bench/traffic/<traffic>.json     the mix: entry driver, scenes, budgets
     bench/drivers/<entry>.py         the driver of that entry point
+    bench/archs/<arch>.py            a counter architecture: its FLOPs,
+                                     weights and plain reference
     bench/metrics/<metric>.py        one reader per per-layer metric
     bench/limits/<workload>.json     the limits of the cell's comparison
     bench/peaks.json                 the chips' peaks, by device kind
@@ -14,6 +16,7 @@ edits none.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -78,6 +81,11 @@ def driver(entry: str):
 
 def metric(name: str):
     return module("metrics", name)
+
+
+@functools.cache  # one module per architecture: callers dispatch per call
+def arch(name: str):
+    return module("archs", name)
 
 
 def per_layer_for(bench: dict, cell: str) -> list:

@@ -167,7 +167,9 @@ class Reference:
 
     def kept(self, key, tiles, role: str, idx):
         """Kept NMS scores (descending) of ``tiles[idx]`` under counter
-        ``role``; cached per (key, role, tile)."""
+        ``role``; cached per (key, role, tile). Decode is per tile, so the
+        padded rows are decoded and left out."""
+        import jax
         import jax.numpy as jnp
         params, spec = self.counters[role]
         todo = sorted({int(i) for i in idx
@@ -176,8 +178,9 @@ class Reference:
             part = todo[s:s + BATCH]
             batch = np.zeros((BATCH, *tiles.shape[1:]), np.float32)
             batch[:len(part)] = tiles[part]
-            raw = np.asarray(self._raw[role](params, jnp.asarray(batch)))
-            boxes, scores = C.decode(raw[:len(part)], spec)
+            raw = jax.tree.map(np.asarray,
+                               self._raw[role](params, jnp.asarray(batch)))
+            boxes, scores = C.decode(raw, spec)
             for j, i in enumerate(part):
                 self._kept[(key, role, i)] = C.kept_scores(
                     boxes[j], scores[j], self.model["nms_iou"])

@@ -37,14 +37,15 @@ class _Spanned:
 
 def detector_config(spec):
     """The program's ``DetectorConfig`` from a counter entry of the
-    benchmark's configuration file."""
+    benchmark's configuration file: each of its fields that the entry
+    gives (lists as tuples), the dataclass's defaults for the others."""
+    import dataclasses
+
     from repro.configs.base import DetectorConfig
-    return DetectorConfig(name=spec["name"], input_size=spec["input_size"],
-                          widths=tuple(spec["widths"]),
-                          n_blocks_per_stage=spec["n_blocks_per_stage"],
-                          n_classes=spec["n_classes"],
-                          n_anchors=spec["n_anchors"],
-                          param_dtype=spec["param_dtype"])
+    given = {f.name: spec[f.name] for f in dataclasses.fields(DetectorConfig)
+             if f.name in spec}
+    return DetectorConfig(**{k: tuple(v) if isinstance(v, list) else v
+                             for k, v in given.items()})
 
 
 def pipeline_config(model, traffic):

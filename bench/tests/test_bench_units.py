@@ -23,9 +23,31 @@ BENCH, ROOT = bench_tiny.BENCH, bench_tiny.ROOT
 ])
 def test_forward_gflops(config, role, gflop):
     cfg = loader.config(config)
-    got = counters.forward_gflops(cfg["counters"][role])
+    spec = cfg["counters"][role]
+    assert "arch" not in spec
+    assert counters.arch(spec) is loader.arch("plain-grid")
+    got = counters.forward_gflops(spec)
+    assert got == loader.arch("plain-grid").forward_gflops(spec)
     assert round(got, 3) == gflop
     assert got == pytest.approx(cfg["gflops_per_tile"][role], rel=1e-12)
+
+
+@pytest.mark.parametrize("config,role", [
+    ("targetfuse-yolov3", "space"), ("targetfuse-yolov3", "ground"),
+    ("targetfuse-ssd", "space"), ("targetfuse-ssd", "ground"),
+])
+def test_detector_config_takes_the_entrys_fields(config, role):
+    """The driver's ``DetectorConfig`` is the one that naming the plain
+    grid's fields one by one gives."""
+    from repro.configs.base import DetectorConfig
+    spec = loader.config(config)["counters"][role]
+    want = DetectorConfig(name=spec["name"], input_size=spec["input_size"],
+                          widths=tuple(spec["widths"]),
+                          n_blocks_per_stage=spec["n_blocks_per_stage"],
+                          n_classes=spec["n_classes"],
+                          n_anchors=spec["n_anchors"],
+                          param_dtype=spec["param_dtype"])
+    assert loader.driver("mission").detector_config(spec) == want
 
 
 def test_forward_gflops_matches_the_program():
@@ -88,12 +110,20 @@ def test_loader_finds_files_by_name():
 
 
 @pytest.mark.parametrize("kind", ["config", "traffic", "metric", "driver",
-                                  "limits"])
+                                  "limits", "arch"])
 def test_loader_refuses_a_missing_name(kind):
     with pytest.raises(loader.MissingFile):
         getattr(loader, kind)("no-such-name")
     with pytest.raises(loader.MissingFile):
         loader.workload(loader.benchmark(), "no-such-cell")
+
+
+def test_unknown_arch_names_the_missing_file():
+    spec = dict(loader.config("targetfuse-yolov3")["counters"]["space"],
+                arch="no-such-arch")
+    with pytest.raises(loader.MissingFile,
+                       match=r"bench/archs/no-such-arch\.py"):
+        counters.forward_gflops(spec)
 
 
 def test_run_exits_nonzero_without_a_tpu():
